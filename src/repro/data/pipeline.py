@@ -112,7 +112,8 @@ class PhaseDataLoader:
         self.source = source
         self.plan = plan
         self.seq_len = seq_len
-        self.mesh = mesh
+        from repro.launch.mesh import gspmd_mesh
+        self.mesh = gspmd_mesh(mesh)
         self.multi_pod = multi_pod
         self.prefetch = prefetch
         self.per_host = per_host

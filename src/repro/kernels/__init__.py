@@ -1,4 +1,4 @@
-from repro.kernels import (backend, flash_attention, ops, ref, rmsnorm,
+from repro.kernels import (backend, flash_attention, paged, ref, rmsnorm,
                            ssd)
 
-__all__ = ["backend", "flash_attention", "ops", "ref", "rmsnorm", "ssd"]
+__all__ = ["backend", "flash_attention", "paged", "ref", "rmsnorm", "ssd"]

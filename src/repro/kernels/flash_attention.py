@@ -21,8 +21,18 @@ ever materialized:
 
 Causal masking skips fully-masked blocks via pl.when in both passes.
 
-VMEM budget per step: q(bq·hd) + k,v(bk·hd) + acc(bq·hd) + s(bq·bk),
-all f32 ⇒ with bq=bk=128, hd=128: ~0.4 MB, well inside ~16 MB VMEM.
+Layout for Mosaic's (8, 128) tiling: the per-row statistics (lse, Δ)
+live in HBM as lane-dense rows ``(B·H, 1, S)`` read and written in
+``(1, 1, block)`` blocks.  Inside the kernels the online-softmax stats
+are ``(bq, 1)`` columns; :func:`_col_to_row` / :func:`_row_to_col`
+convert between the two with an exact masked reduction (one nonzero
+per output element), so no in-kernel transpose is needed.  The dk/dv
+kernel works in the transposed frame (``sᵀ = k·qᵀ``), where the row
+stats broadcast directly.
+
+VMEM budget per step: q(bq·hd) + k,v(bk·hd) + acc(bq·hd) + s(bq·bk) +
+the (bq, bq) conversion mask, all f32 ⇒ with bq=bk=128, hd=128:
+~0.5 MB, well inside the 16 MiB default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -35,6 +45,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _col_to_row(col):
+    """(n, 1) → (1, n) as a masked sublane sum (exact)."""
+    n = col.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _row_to_col(row):
+    """(1, n) → (n, 1) as a masked lane sum (exact)."""
+    n = row.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
 def _validate_blocks(S: int, block_q: int, block_k: int):
@@ -78,15 +104,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             kpos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_scr[...]
+        m_prev = m_scr[...]                         # (bq, 1)
         l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=1)
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr[:, None] + p @ v
+        acc_scr[...] = acc_scr[...] * corr + p @ v
 
     if causal:
         # a k-block is fully masked iff its first key position exceeds
@@ -97,10 +123,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(ki == n_k - 1)
     def _finish():
-        l = l_scr[...]
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[...] + jnp.log(jnp.maximum(l, 1e-30))
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = _col_to_row(m_scr[...] + jnp.log(l))
 
 
 def _kv_index(H: int, Hkv: int, G: int):
@@ -116,7 +141,7 @@ def _kv_index(H: int, Hkv: int, G: int):
 
 def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
                interpret: bool):
-    """Returns (out (B,H,S,hd), lse (B·H, S) f32)."""
+    """Returns (out (B,H,S,hd), lse (B·H, 1, S) f32)."""
     B, H, S, hd = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
@@ -142,15 +167,15 @@ def _flash_fwd(q, k, v, *, causal: bool, block_q: int, block_k: int,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * H, S), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
@@ -173,8 +198,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0].astype(jnp.float32)            # (bk, hd)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)          # (bq, hd)
-        lse = lse_ref[0]                            # (bq,) f32
-        delta = delta_ref[0]                        # (bq,) f32
+        lse = _row_to_col(lse_ref[0])               # (bq, 1) f32
+        delta = _row_to_col(delta_ref[0])           # (bq, 1) f32
         s = q @ k.T * sm_scale
         if causal:
             qpos = qi * block_q + jax.lax.broadcasted_iota(
@@ -182,9 +207,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kpos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])               # masked entries → 0
+        p = jnp.exp(s - lse)                        # masked entries → 0
         dp = do @ v.T                               # (bq, bk)
-        ds = p * (dp - delta[:, None]) * sm_scale
+        ds = p * (dp - delta) * sm_scale
         dq_scr[...] += ds @ k
 
     if causal:
@@ -213,20 +238,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0].astype(jnp.float32)            # (bk, hd)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)          # (bq, hd)
-        lse = lse_ref[0]
+        lse = lse_ref[0]                            # (1, bq) f32
         delta = delta_ref[0]
-        s = q @ k.T * sm_scale                      # (bq, bk)
+        st = k @ q.T * sm_scale                     # sᵀ: (bk, bq)
         if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
             kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dv_scr[...] += p.T @ do                     # (bk, hd)
-        dp = do @ v.T
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk_scr[...] += ds.T @ q
+                jnp.int32, (block_k, block_q), 0)
+            qpos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(kpos <= qpos, st, NEG_INF)
+        pt = jnp.exp(st - lse)                      # pᵀ
+        dv_scr[...] += pt @ do                      # (bk, hd)
+        dpt = v @ do.T                              # dpᵀ: (bk, bq)
+        dst = pt * (dpt - delta) * sm_scale
+        dk_scr[...] += dst @ q
 
     if causal:
         # a q-block contributes iff its last query can see this k-block
@@ -253,11 +278,12 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal: bool, block_q: int,
     vf = v.reshape(B * Hkv, S, hd)
     dof = do.reshape(B * H, S, hd)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(B * H, S)
+                    axis=-1).reshape(B * H, 1, S)
     kv_index = _kv_index(H, Hkv, G)
 
     q_spec = pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi))
+    row_spec = pl.BlockSpec((1, 1, block_q),
+                            lambda bh, qi, ki: (bh, 0, qi))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
@@ -281,7 +307,8 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal: bool, block_q: int,
     # dk/dv grid iterates q innermost; q-indexed operands read block qi
     # (grid position 2), kv-indexed operands block ki (position 1)
     qT_spec = pl.BlockSpec((1, block_q, hd), lambda bh, ki, qi: (bh, qi, 0))
-    rowT_spec = pl.BlockSpec((1, block_q), lambda bh, ki, qi: (bh, qi))
+    rowT_spec = pl.BlockSpec((1, 1, block_q),
+                             lambda bh, ki, qi: (bh, 0, qi))
     kvT_index = _kv_index(H, Hkv, G)
     k_spec = pl.BlockSpec((1, block_k, hd),
                           lambda bh, ki, qi: kvT_index(bh, qi, ki))

@@ -6,8 +6,11 @@ One query token per sequence (the token just written at position
 holds absolute position ``s``.  Grid is (batch·heads, k-blocks) with the
 online-softmax running stats (m, l, acc) in VMEM scratch across the
 k iterations, exactly like the flash forward; GQA is folded into the K/V
-BlockSpec index map (query head h reads kv head h // group).  Per-request
-lengths sit in SMEM — the mask ``kpos <= lengths[b]`` implements the
+BlockSpec index map (query head h reads kv head h // group).  The
+query and output rows are ``(1, 1, hd)`` blocks of ``(B·H, 1, hd)``
+arrays (full-width last two dims, as Mosaic's tiling requires) and the
+running stats are ``(1, 1)``.  Per-request lengths are scalar-prefetched
+into SMEM — the mask ``kpos <= lengths[b]`` implements the
 repo's zero-padding convention: page remainders, stale slots from evicted
 requests, and block padding all live at positions the causal reach never
 touches, so they contribute exactly zero.
@@ -29,8 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _ragged_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, block_k: int, n_k: int, scale: float):
+def _ragged_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, block_k: int, n_k: int, n_heads: int,
+                   scale: float):
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -39,10 +43,10 @@ def _ragged_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    ln = len_ref[0, 0]                              # this request's length
+    ln = len_ref[pl.program_id(0) // n_heads]       # this request's length
 
     def compute():
-        q = q_ref[...].astype(jnp.float32)          # (1, hd)
+        q = q_ref[0].astype(jnp.float32)            # (1, hd)
         k = k_ref[0].astype(jnp.float32)            # (bk, hd)
         v = v_ref[0].astype(jnp.float32)
         s = q @ k.T * scale                         # (1, bk)
@@ -51,13 +55,13 @@ def _ragged_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
         s = jnp.where(kpos <= ln, s, NEG_INF)
         m_prev = m_scr[...]
         l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)   # (1, 1)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=1)
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr[:, None] + p @ v
+        acc_scr[...] = acc_scr[...] * corr + p @ v
 
     # a k-block is fully masked iff its first key position exceeds the
     # request's causal reach (position `ln` holds the newest token)
@@ -65,9 +69,8 @@ def _ragged_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
 
     @pl.when(ki == n_k - 1)
     def _finish():
-        l = l_scr[...]
-        o_ref[...] = (acc_scr[...] /
-                      jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def ragged_decode_attention(q, k, v, lengths, *, block_k: int = 128,
@@ -94,34 +97,38 @@ def ragged_decode_attention(q, k, v, lengths, *, block_k: int = 128,
     n_k = Skv // block_k
     scale = 1.0 / math.sqrt(hd)
 
-    qf = q.reshape(B * H, hd)
+    qf = q.reshape(B * H, 1, hd)
     kf = k.reshape(B * Hkv, Skv, hd)
     vf = v.reshape(B * Hkv, Skv, hd)
-    lens = jnp.reshape(lengths, (B, 1)).astype(jnp.int32)
+    lens = jnp.reshape(lengths, (B,)).astype(jnp.int32)
 
-    def kv_index(bh, ki):
+    # index maps receive the scalar-prefetched lengths as a last arg
+    def kv_index(bh, ki, _lens):
         b = bh // H
         hkv = (bh % H) // G
         return (b * Hkv + hkv, ki, 0)
 
+    def row_index(bh, ki, _lens):
+        return (bh, 0, 0)
+
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, block_k=block_k, n_k=n_k,
-                          scale=scale),
-        grid=(B * H, n_k),
-        in_specs=[
-            pl.BlockSpec((1, hd), lambda bh, ki: (bh, 0)),
-            pl.BlockSpec((1, block_k, hd), kv_index),
-            pl.BlockSpec((1, block_k, hd), kv_index),
-            pl.BlockSpec((1, 1), lambda bh, ki: (bh // H, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, hd), lambda bh, ki: (bh, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
-        ],
+                          n_heads=H, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, n_k),
+            in_specs=[
+                pl.BlockSpec((1, 1, hd), row_index),
+                pl.BlockSpec((1, block_k, hd), kv_index),
+                pl.BlockSpec((1, block_k, hd), kv_index),
+            ],
+            out_specs=pl.BlockSpec((1, 1, hd), row_index),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B * H, 1, hd), q.dtype),
         interpret=interpret,
-    )(qf, kf, vf, lens)
+    )(lens, qf, kf, vf)
     return out.reshape(B, H, hd)

@@ -14,7 +14,8 @@ it: one fma per element vs an extra HBM round-trip):
     dscale = Σ_rows g · x̂
 
 dscale is accumulated as one (1, d) partial per row-block, written to a
-(n_blocks, d) f32 output and summed in XLA — no cross-block scratch
+(n_blocks, 1, d) f32 output (a full-width (1, d) block per step, which
+Mosaic's (8, 128) tiling accepts) and summed in XLA — no cross-block scratch
 carry, so the grid stays embarrassingly parallel.  Zero-padded tail
 rows contribute exactly zero to both dx and the dscale partials.
 """
@@ -45,7 +46,7 @@ def _rmsnorm_bwd_kernel(x_ref, s_ref, g_ref, dx_ref, dscale_ref, *,
     gs = g * (1.0 + s_ref[...].astype(jnp.float32))
     proj = jnp.mean(gs * xh, axis=-1, keepdims=True)
     dx_ref[...] = (rr * (gs - xh * proj)).astype(dx_ref.dtype)
-    dscale_ref[...] = jnp.sum(g * xh, axis=0, keepdims=True)
+    dscale_ref[0] = jnp.sum(g * xh, axis=0, keepdims=True)
 
 
 def _pallas_fwd(x, scale, *, eps, block_rows, interpret):
@@ -102,17 +103,17 @@ def _pallas_bwd(x, scale, g, *, eps, block_rows, interpret):
         ],
         out_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(xf.shape, x.dtype),
-            jax.ShapeDtypeStruct((n, d), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, d), jnp.float32),
         ],
         interpret=interpret,
     )(xf, scale, gf)
     if pad:
         dx = dx[:rows]
-    dscale = jnp.sum(dscale_parts, axis=0).astype(scale.dtype)
+    dscale = jnp.sum(dscale_parts, axis=(0, 1)).astype(scale.dtype)
     return dx.reshape(orig_shape), dscale
 
 

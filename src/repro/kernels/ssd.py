@@ -13,8 +13,9 @@ The cheap cross-chunk recurrence (nc sequential steps on (N,P) states)
 and the rank-1 inter-chunk correction stay in XLA — they are O(S·N·P)
 vs the kernel's O(S·Q·(N+P)) and do not benefit from manual tiling.
 
-VMEM per cell (Q=128, N=128, P=64, f32): x 32 KB + B/C 2·64 KB +
-masks/CB 2·64 KB + outputs ~96 KB ⇒ < 0.5 MB.
+VMEM per cell (Q=256, N=128, P=64, f32): x 64 KB + B/C 2·128 KB +
+five (Q,Q) tiles (masks, decay, CB, M) 5·256 KB + outputs ~96 KB
+⇒ < 2 MB, inside the 16 MiB default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -23,33 +24,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref,
-                      t_ref):
-    """Blocks: x (Q,P); dt (Q,); a (1,) scalar A for this head;
-    b, c (Q,N); outputs y (Q,P), s (N,P), t (1,)."""
+def _ssd_chunk_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref,
+                      t_ref, *, n_heads: int):
+    """Blocks: a (H,) in SMEM; x (Q,P); dt (1,Q) as a lane row; b, c
+    (Q,N) shared by the chunk's heads; outputs y (Q,P), s (N,P),
+    t (1,1).
+
+    The chunk cumsum is a masked lane sum over a (Q, Q) tile, and the
+    row↔column moves are masked sums over the identity — every step is
+    a plain VPU reduction, so the kernel needs no in-kernel transpose
+    or 1-D vector and stays exact in f32."""
+    A = a_ref[pl.program_id(0) % n_heads]
     x = x_ref[0].astype(jnp.float32)                      # (Q,P)
-    dt = dt_ref[0].astype(jnp.float32)                    # (Q,)
-    A = a_ref[0]
+    dt = dt_ref[0].astype(jnp.float32)                    # (1,Q)
     Bm = b_ref[0].astype(jnp.float32)                     # (Q,N)
     Cm = c_ref[0].astype(jnp.float32)
 
-    l = dt * A                                            # (Q,)
-    cum = jnp.cumsum(l)                                   # inclusive
-    T = cum[-1]
-
     Q = x.shape[0]
-    diff = cum[:, None] - cum[None, :]                    # (i,j)
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    decay = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    causal = jj <= ii
+    eye = jj == ii
+    l = dt * A                                            # (1,Q)
+    cum = jnp.sum(jnp.where(causal, l, 0.0), axis=1,
+                  keepdims=True)                          # (Q,1) inclusive
+    cum_row = jnp.sum(jnp.where(eye, cum, 0.0), axis=0,
+                      keepdims=True)                      # (1,Q)
+    dt_col = jnp.sum(jnp.where(eye, dt, 0.0), axis=1, keepdims=True)
+    T = jnp.sum(l, axis=1, keepdims=True)                 # (1,1)
+
+    decay = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)  # (i,j)
     CB = Cm @ Bm.T                                        # (Q,Q) MXU
-    M = CB * decay * dt[None, :]
+    M = CB * decay * dt
     y_ref[0] = (M @ x).astype(y_ref.dtype)                # (Q,P) MXU
 
-    sdecay = jnp.exp(T - cum) * dt                        # (Q,)
-    s_ref[0] = ((Bm * sdecay[:, None]).T @ x).astype(s_ref.dtype)
+    sdecay = jnp.exp(T - cum) * dt_col                    # (Q,1)
+    s_ref[0] = ((Bm * sdecay).T @ x).astype(s_ref.dtype)
     t_ref[0] = T.astype(t_ref.dtype)
 
 
@@ -67,40 +80,38 @@ def ssd_chunk(xh, dt, A, Bm, Cm, *, chunk: int = 128,
     assert S % Q == 0
     nc = S // Q
 
-    # layout: (B, nc, H, Q, ...) so each grid cell is one (b, c, h)
+    # layout: (B, nc, H, Q, ...) so each grid cell is one (b, c, h);
+    # B and C are shared by the heads of a chunk, so their index map
+    # reads block g // H instead of materializing H copies
     x_l = jnp.moveaxis(xh.reshape(B, nc, Q, H, Pd), 3, 2) \
         .reshape(B * nc * H, Q, Pd)
     dt_l = jnp.moveaxis(dt.reshape(B, nc, Q, H), 3, 2) \
-        .reshape(B * nc * H, Q)
-    b_l = jnp.broadcast_to(Bm.reshape(B, nc, 1, Q, N),
-                           (B, nc, H, Q, N)).reshape(B * nc * H, Q, N)
-    c_l = jnp.broadcast_to(Cm.reshape(B, nc, 1, Q, N),
-                           (B, nc, H, Q, N)).reshape(B * nc * H, Q, N)
-    a_l = jnp.broadcast_to(A[None, None, :],
-                           (B, nc, H)).reshape(B * nc * H)
+        .reshape(B * nc * H, 1, Q)
+    b_l = Bm.reshape(B * nc, Q, N)
+    c_l = Cm.reshape(B * nc, Q, N)
 
     y, s, t = pl.pallas_call(
-        _ssd_chunk_kernel,
+        functools.partial(_ssd_chunk_kernel, n_heads=H),
         grid=(B * nc * H,),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, Q, Pd), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, Q), lambda g: (g, 0)),
-            pl.BlockSpec((1,), lambda g: (g,)),
-            pl.BlockSpec((1, Q, N), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, Q, N), lambda g: (g, 0, 0)),
+            pl.BlockSpec((1, 1, Q), lambda g: (g, 0, 0)),
+            pl.BlockSpec((1, Q, N), lambda g: (g // H, 0, 0)),
+            pl.BlockSpec((1, Q, N), lambda g: (g // H, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, Q, Pd), lambda g: (g, 0, 0)),
             pl.BlockSpec((1, N, Pd), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1,), lambda g: (g,)),
+            pl.BlockSpec((1, 1, 1), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * nc * H, Q, Pd), jnp.float32),
             jax.ShapeDtypeStruct((B * nc * H, N, Pd), jnp.float32),
-            jax.ShapeDtypeStruct((B * nc * H,), jnp.float32),
+            jax.ShapeDtypeStruct((B * nc * H, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x_l, dt_l, a_l, b_l, c_l)
+    )(A.astype(jnp.float32), x_l, dt_l, b_l, c_l)
 
     y = jnp.moveaxis(y.reshape(B, nc, H, Q, Pd), 2, 3).reshape(B, S, H, Pd)
     s = s.reshape(B, nc, H, N, Pd)

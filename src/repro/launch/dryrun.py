@@ -27,7 +27,7 @@ import jax               # noqa: E402
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config  # noqa: E402
 from repro.launch import steps as ST     # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import auto_mesh, make_production_mesh  # noqa: E402
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -118,7 +118,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         if mesh_shape:
             dims = tuple(int(x) for x in mesh_shape.split("x"))
             names = ("data", "model") if len(dims) == 2 else                 ("pod", "data", "model")
-            mesh = jax.make_mesh(dims, names)
+            mesh = auto_mesh(dims, names)
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         fn, args, in_specs, out_specs = ST.build_workload(
@@ -126,7 +126,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
             seq_shard=seq_shard, remat_policy=remat_policy,
             serve_resident=serve_resident,
             cache_seq_shard=cache_seq_shard)
-        with mesh:
+        with jax.set_mesh(mesh):
             in_sh = ST._named(mesh, in_specs)
             out_sh = ST._named(mesh, out_specs)
             # donate params/opt (train) or cache (decode) exactly like the

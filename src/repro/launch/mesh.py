@@ -11,6 +11,7 @@ multi-pod adds a leading 'pod' axis over DCN.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e per-chip constants used by the roofline (benchmarks/roofline.py)
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
@@ -92,6 +93,31 @@ def assert_per_host_row_blocks(mesh, process_count: int | None = None):
     check_per_host_row_blocks(per, n, nproc)
 
 
+def auto_mesh(dims, names):
+    """A device mesh whose axes are all ``Auto``.  The programs here
+    place state with ``NamedSharding`` in/out shardings and sharding
+    constraints, and leave every intermediate to GSPMD propagation;
+    ``jax.make_mesh``'s default ``Explicit`` axes would instead type
+    each intermediate's sharding (the embedding gather of a
+    ``P("model", "data")`` table by ``data``-sharded tokens would then
+    name ``data`` twice)."""
+    return jax.make_mesh(tuple(dims), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(dims))
+
+
+def gspmd_mesh(mesh):
+    """``mesh`` with every axis ``Auto`` — the identity for meshes built
+    here, so a caller may also hand in ``jax.make_mesh``'s default
+    explicit mesh.  Anything that is not a ``Mesh`` (``None``, shape-only
+    stand-ins) passes through."""
+    if (not isinstance(mesh, jax.sharding.Mesh)
+            or all(t == AxisType.Auto for t in mesh.axis_types)):
+        return mesh
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                             axis_types=(AxisType.Auto,)
+                             * len(mesh.axis_names))
+
+
 def make_launch_mesh(spec: str | None, *, distributed: bool = False):
     """The launcher's mesh from a ``--mesh`` spec ("DxM" data x model,
     or "PxDxM" pod x data x model), or the default multi-process
@@ -102,22 +128,21 @@ def make_launch_mesh(spec: str | None, *, distributed: bool = False):
         dims = [int(x) for x in spec.split("x")]
         names = ("data", "model")[:len(dims)] if len(dims) == 2 \
             else ("pod", "data", "model")
-        return jax.make_mesh(tuple(dims), names)
+        return auto_mesh(dims, names)
     if distributed:
-        return jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+        return auto_mesh((jax.device_count(), 1), ("data", "model"))
     return None
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2,
                    multi_pod: bool = False):
     """Small mesh for CI-scale dry-run tests (8 host devices)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return auto_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))

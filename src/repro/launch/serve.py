@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as R
 from repro.serving import GenerationRequest, ServingEngine
 
@@ -43,6 +44,7 @@ def main(argv=None):
                     help="suppress per-request completion lines")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced()
     if args.kernel_backend:
         cfg = dataclasses.replace(cfg,

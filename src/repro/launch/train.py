@@ -294,7 +294,10 @@ def main():
     from repro.configs import (OptimizerConfig, RunConfig, ScheduleConfig,
                                get_config)
     from repro.data import MarkovLM, PhaseDataLoader
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train.trainer import Trainer
+
+    enable_compile_cache()
 
     if distributed:
         print(f"jax.distributed: process {jax.process_index()}"

@@ -23,11 +23,12 @@ def fsdp_axis(multi_pod: bool):
 
 
 def constrain(x, spec: P):
-    """with_sharding_constraint that is a no-op outside a mesh context."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
+    """``with_sharding_constraint`` against the context mesh
+    (``jax.set_mesh``).  Skipped only when no mesh is set — the
+    single-device path; on a mesh every error surfaces."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def residual_spec(batch_axes, seq_len: int) -> P:

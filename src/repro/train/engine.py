@@ -32,6 +32,7 @@ re-exported by ``launch.steps`` for the dry-run.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -350,7 +351,8 @@ class PhaseEngine:
         self.model = cfg.resolved_model()
         self.optimizer = optimizer
         self.plan = plan
-        self.mesh = mesh
+        from repro.launch.mesh import gspmd_mesh
+        self.mesh = gspmd_mesh(mesh)
         self.multi_pod = multi_pod
         self.max_device_batch = max_device_batch
         self.adaptive = plan.kind == "adaptive-seesaw"
@@ -374,6 +376,14 @@ class PhaseEngine:
                             threading.Thread] = {}
 
     # -- mesh geometry -------------------------------------------------- #
+    def mesh_context(self):
+        """The context every step traces, compiles and runs under: on a
+        mesh, ``jax.set_mesh`` — the bare ``PartitionSpec``s of the
+        model's sharding constraints resolve against it."""
+        if isinstance(self.mesh, jax.sharding.Mesh):
+            return jax.set_mesh(self.mesh)
+        return contextlib.nullcontext()
+
     def n_data_devices(self) -> int:
         from repro.launch.mesh import data_parallel_size
         return data_parallel_size(self.mesh)
@@ -619,7 +629,8 @@ class PhaseEngine:
 
         def work():
             try:
-                self._cache[key] = jitted.lower(*structs).compile()
+                with self.mesh_context():
+                    self._cache[key] = jitted.lower(*structs).compile()
             except Exception:
                 self._cache.setdefault(key, jitted)
 
@@ -672,9 +683,10 @@ class PhaseEngine:
         scalars = (jnp.float32(float(tokens_seen)),
                    jnp.int32(-1 if step is None else int(step)),
                    jnp.int32(int(n_valid)))
-        if self.adaptive:
-            ema0 = jnp.float32(
-                -1.0 if loss_ema is None else float(loss_ema))
-            return fn(params, opt_state, *scalars, ema0,
-                      stacked_batch, *self._lr_tables())
-        return fn(params, opt_state, *scalars, stacked_batch)
+        with self.mesh_context():
+            if self.adaptive:
+                ema0 = jnp.float32(
+                    -1.0 if loss_ema is None else float(loss_ema))
+                return fn(params, opt_state, *scalars, ema0,
+                          stacked_batch, *self._lr_tables())
+            return fn(params, opt_state, *scalars, stacked_batch)

@@ -122,13 +122,16 @@ class Trainer:
         # resolved_model() also fail-fasts a bad --kernel-backend here
         params = R.init_params(key, cfg.resolved_model())
         opt_state = self.optimizer.init(params)
-        # single-process runs skip this: jit's in_shardings place the
-        # state directly, without a host round-trip of every leaf
-        if jax.process_count() > 1:
-            sh = self.engine.state_shardings()
-            if sh is not None:
-                params = _place_like(params, sh[0])
-                opt_state = _place_like(opt_state, sh[1])
+        # on a mesh the state starts on its step shardings: a first
+        # call that placed it through in_shardings would hand the second
+        # call differently-sharded arguments, and the step would trace
+        # and compile twice
+        sh = self.engine.state_shardings()
+        if sh is not None and jax.process_count() > 1:
+            params = _place_like(params, sh[0])
+            opt_state = _place_like(opt_state, sh[1])
+        elif sh is not None:
+            params, opt_state = jax.device_put((params, opt_state), sh)
         self.state = TrainState(params, opt_state)
         self.history: List[Dict[str, float]] = []
         self._ckpt_manager: Optional[CKPT.CheckpointManager] = None

@@ -30,7 +30,7 @@ def loss_of(p, b):
 # sharded: params sharded per param_specs, batch over data
 mesh = make_test_mesh(2, 2)
 pspec = R.param_specs(cfg)
-with mesh:
+with jax.set_mesh(mesh):
     p_sh = jax.device_put(params, jax.tree.map(
         lambda s: NamedSharding(mesh, s), pspec,
         is_leaf=lambda x: isinstance(x, P)))

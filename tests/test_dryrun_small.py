@@ -31,14 +31,12 @@ shape = {
 mesh = make_test_mesh(2, 2, multi_pod=multipod)
 fn, args, in_specs, out_specs = ST.build_workload(
     cfg, shape, multi_pod=multipod)
-with mesh:
+with jax.set_mesh(mesh):
     in_sh = ST._named(mesh, in_specs)
     out_sh = ST._named(mesh, out_specs)
     lowered = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh).lower(*args)
     compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):      # older jax: one dict per device
-    ca = ca[0] if ca else {}
 print(json.dumps({"ok": True, "flops": float(ca.get("flops", -1))}))
 """
 
