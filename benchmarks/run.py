@@ -2,8 +2,7 @@
 
     PYTHONPATH=src python -m benchmarks.run [--only figure1]
 
-Prints ``name,us_per_call,derived`` CSV.  The roofline table (§g) is a
-separate artifact: ``python -m benchmarks.roofline``.
+Prints ``name,us_per_call,derived`` CSV.
 """
 from __future__ import annotations
 

@@ -3,8 +3,8 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
-on the production meshes and extract the roofline terms.  The train
-workload comes from the phase execution engine's step builder (via
+on the production meshes and record what the compiler reports.  The
+train workload comes from the phase execution engine's step builder (via
 ``launch.steps.build_workload``) — the same compiled step the Trainer
 dispatches, so the dry-run's memory/collective analysis describes the
 real hot path.
@@ -57,8 +57,8 @@ def _bytes_of_shapes(text: str) -> int:
 def collective_bytes(hlo_text: str):
     """Sum result bytes of every collective op in the optimized HLO, per
     collective kind, split by whether the op sits inside a loop body
-    (lax.scan over layers ⇒ the roofline multiplies loop-body bytes by
-    the trip count).  Result size ≈ bytes moved per device."""
+    (lax.scan over layers ⇒ loop-body bytes repeat once per trip).
+    Result size ≈ bytes moved per device."""
     out = {k: 0 for k in _COLLECTIVES}
     out_loop = {k: 0 for k in _COLLECTIVES}
     counts = {k: 0 for k in _COLLECTIVES}

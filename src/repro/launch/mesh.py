@@ -13,11 +13,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import AxisType
 
-# TPU v5e per-chip constants used by the roofline (benchmarks/roofline.py)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
-
 
 def data_parallel_size(mesh) -> int:
     """Number of data-parallel shards of the global batch: the product

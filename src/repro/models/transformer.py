@@ -85,11 +85,13 @@ def _layer(pl: Params, x, cfg: ModelConfig, *, res_spec,
     batch_axes = res_spec[0] if isinstance(res_spec, P) else None
     kb = cfg.kernel_backend
     h = rmsnorm(x, pl["norm1"], cfg.norm_eps, backend=kb)
-    a, _ = A.attn_forward(pl["attn"], h, n_heads=cfg.n_heads,
-                          n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-                          rope_theta=cfg.rope_theta, causal=True,
-                          window=cfg.sliding_window, chunk=chunk,
-                          block_skip=block_skip, backend=kb)
+    with jax.named_scope("attention"):
+        a, _ = A.attn_forward(pl["attn"], h, n_heads=cfg.n_heads,
+                              n_kv_heads=cfg.n_kv_heads,
+                              head_dim=cfg.head_dim,
+                              rope_theta=cfg.rope_theta, causal=True,
+                              window=cfg.sliding_window, chunk=chunk,
+                              block_skip=block_skip, backend=kb)
     x = x + a
     x = constrain(x, res_spec)
     h = rmsnorm(x, pl["norm2"], cfg.norm_eps, backend=kb)

@@ -96,8 +96,13 @@ def make_grad_step(cfg: ModelConfig, optimizer: O.Optimizer, *,
     remat_policy, …) forward to the family loss function."""
 
     def loss_of(params, batch):
-        return R.loss_fn(params, cfg, batch, z_loss=z_loss, dtype=dtype,
-                         remat=remat, multi_pod=multi_pod, **loss_kw)
+        # named scopes mark the HLO metadata only: the device trace
+        # splits the step into forward, its transpose (backward) and
+        # the optimizer by these names
+        with jax.named_scope("forward"):
+            return R.loss_fn(params, cfg, batch, z_loss=z_loss,
+                             dtype=dtype, remat=remat, multi_pod=multi_pod,
+                             **loss_kw)
 
     grad_fn = jax.value_and_grad(loss_of, has_aux=True)
 
@@ -121,11 +126,13 @@ def make_grad_step(cfg: ModelConfig, optimizer: O.Optimizer, *,
             metrics = jax.tree.map(jnp.mean, metrics)
         else:
             (loss, metrics), grads = grad_fn(params, batch)
-        new_params, new_opt = optimizer.update(grads, opt_state, params,
-                                               lr)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt_state,
+                                                   params, lr)
+            grad_norm = O._global_norm(grads)
         metrics = {k: jnp.asarray(v, jnp.float32)
                    for k, v in metrics.items()}
-        metrics["grad_norm"] = O._global_norm(grads)
+        metrics["grad_norm"] = grad_norm
         return new_params, new_opt, metrics
 
     return step
@@ -177,8 +184,10 @@ def make_fused_step(grad_step: Callable, lr_fn: Callable,
                     tokens_per_step: float, *,
                     ema_decay: Optional[float] = None,
                     n_lr_args: int = 0) -> Callable:
-    """Wrap a grad step into ``fused(params, opt_state, tokens_seen,
-    step0, n_valid, batches)`` where ``batches`` has a leading K dim.
+    """Wrap a grad step into ``train_step(params, opt_state,
+    tokens_seen, step0, n_valid, batches)`` where ``batches`` has a
+    leading K dim (both variants carry that name, so the compiled
+    program is ``jit_train_step``).
     One host dispatch covers up to K optimizer steps; metrics (plus the
     per-step ``lr``) return stacked ``(K,)``.
 
@@ -187,7 +196,7 @@ def make_fused_step(grad_step: Callable, lr_fn: Callable,
     untouched):
 
     - ``ema_decay`` — carry a loss EMA through the scan:  the signature
-      becomes ``fused(params, opt_state, tokens_seen, step0, n_valid,
+      becomes ``train_step(params, opt_state, tokens_seen, step0, n_valid,
       ema0, batches, *lr_args)`` returning ``(params, opt_state,
       metrics, ema)``.  The EMA is one f32 scalar updated per *valid*
       step (``ema ← d·ema + (1−d)·loss``; padded tail steps leave it
@@ -254,8 +263,8 @@ def make_fused_step(grad_step: Callable, lr_fn: Callable,
         return lr_fn(tok, stepi) if takes_step else lr_fn(tok)
 
     if ema_decay is None:
-        def fused(params, opt_state, tokens_seen, step0, n_valid,
-                  batches, *lr_args):
+        def train_step(params, opt_state, tokens_seen, step0, n_valid,
+                       batches, *lr_args):
             real, skip = _make_real(params, opt_state, batches)
 
             def body(carry, batch):
@@ -271,12 +280,12 @@ def make_fused_step(grad_step: Callable, lr_fn: Callable,
                                                            batches)
             return params, opt_state, metrics
 
-        return fused
+        return train_step
 
     decay = jnp.float32(ema_decay)
 
-    def fused_ema(params, opt_state, tokens_seen, step0, n_valid,
-                  ema0, batches, *lr_args):
+    def train_step(params, opt_state, tokens_seen, step0, n_valid,
+                   ema0, batches, *lr_args):
         real, skip = _make_real(params, opt_state, batches)
 
         def body(carry, batch):
@@ -299,7 +308,7 @@ def make_fused_step(grad_step: Callable, lr_fn: Callable,
             body, carry, batches)
         return params, opt_state, metrics, ema
 
-    return fused_ema
+    return train_step
 
 
 def _takes_step(lr_fn: Callable) -> bool:

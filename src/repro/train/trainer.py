@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import RunConfig
 from repro.core.seesaw import build_plan
@@ -301,34 +302,39 @@ class Trainer:
                        jax.tree.map(lambda *xs: jnp.stack(xs), *buf),
                        len(buf))
 
-    def _flush(self, pending, log_cb):
+    def _flush(self, pending, log_cb, t0: float):
         """Device→host metric transfer, deferred to log boundaries.
         A merged chunk can span a phase boundary (same batch size,
         different LR scale), so each step's phase is attributed from
         its token count, not the chunk's head phase.  Metric rows past
         a chunk's ``n`` real steps are device-side padding and are
-        never read."""
+        never read.  A step's ``wall`` is the time since ``t0`` at
+        which its metrics reached the host, so the step had run."""
+        if not pending:
+            return
         le = max(self.cfg.log_every, 1)
-        for base_step, base_tok, phase, wall, metrics, n in pending:
-            host = jax.device_get(metrics)
-            tok_per_step = phase.batch_size * self.cfg.seq_len
-            for i in range(n):
-                tok_start = base_tok + i * tok_per_step
-                ph = self.plan.realized_phase_at(tok_start,
-                                                 self.cfg.seq_len)
-                rec = {"step": base_step + i + 1,
-                       "tokens": base_tok + (i + 1) * tok_per_step,
-                       "lr": float(host["lr"][i]),
-                       "batch_size": phase.batch_size,
-                       "phase": ph.index,
-                       "loss": float(host["loss"][i]),
-                       "wall": wall}
-                for name, v in host.items():
-                    if name not in ("loss", "lr"):
-                        rec[name] = float(v[i])
-                self.history.append(rec)
-                if log_cb and rec["step"] % le == 0:
-                    log_cb(rec)
+        with TraceAnnotation("repro.train.sync"):
+            for base_step, base_tok, phase, metrics, n in pending:
+                host = jax.device_get(metrics)
+                wall = time.time() - t0
+                tok_per_step = phase.batch_size * self.cfg.seq_len
+                for i in range(n):
+                    tok_start = base_tok + i * tok_per_step
+                    ph = self.plan.realized_phase_at(tok_start,
+                                                     self.cfg.seq_len)
+                    rec = {"step": base_step + i + 1,
+                           "tokens": base_tok + (i + 1) * tok_per_step,
+                           "lr": float(host["lr"][i]),
+                           "batch_size": phase.batch_size,
+                           "phase": ph.index,
+                           "loss": float(host["loss"][i]),
+                           "wall": wall}
+                    for name, v in host.items():
+                        if name not in ("loss", "lr"):
+                            rec[name] = float(v[i])
+                    self.history.append(rec)
+                    if log_cb and rec["step"] % le == 0:
+                        log_cb(rec)
         pending.clear()
 
     def run(self, loader, max_steps: Optional[int] = None,
@@ -369,39 +375,51 @@ class Trainer:
         rechunk = True
         while rechunk and not stop:
             rechunk = False
-            for phase, stacked, n in self._chunks(loader, max_steps):
-                if self._ckpt_manager is not None:
-                    self._ckpt_manager.check()
-                out = self.engine.run_chunk(
-                    st.params, st.opt_state, st.tokens_seen, stacked,
-                    n_valid=n, step=st.step, loss_ema=st.loss_ema)
-                if self.controller is not None:
-                    params, opt_state, metrics, ema = out
-                    st.loss_ema = float(jax.device_get(ema))
-                else:
-                    params, opt_state, metrics = out
-                base_step, base_tok = st.step, st.tokens_seen
-                st.params, st.opt_state = params, opt_state
-                st.step += n
-                st.tokens_seen += n * phase.batch_size * self.cfg.seq_len
-                pending.append((base_step, base_tok, phase,
-                                time.time() - t0, metrics, n))
-                if (self.controller is not None
-                        and self.controller.observe_smoothed(
-                            st.loss_ema, n)):
-                    self._fire_cut(loader, stacked)
-                    rechunk = True
-                if st.step // le > base_step // le:
-                    self._flush(pending, log_cb)
-                if (se and checkpoint_path
-                        and st.step // se > base_step // se):
-                    self.save_checkpoint(checkpoint_path,
-                                         block=not async_save)
-                if stop_fn is not None and stop_fn():
-                    stop = True
-                if rechunk or stop:
-                    break
-        self._flush(pending, log_cb)
+            chunks = self._chunks(loader, max_steps)
+            while not (rechunk or stop):
+                # one span per pass covers every host moment from one
+                # dispatch to the next (the last pass finds the stream
+                # empty); its children name the parts
+                with StepTraceAnnotation("repro.train.step",
+                                         step_num=st.step):
+                    with TraceAnnotation("repro.train.next_chunk"):
+                        item = next(chunks, None)
+                    if item is None:
+                        break
+                    phase, stacked, n = item
+                    if self._ckpt_manager is not None:
+                        self._ckpt_manager.check()
+                    with TraceAnnotation("repro.train.dispatch"):
+                        out = self.engine.run_chunk(
+                            st.params, st.opt_state, st.tokens_seen,
+                            stacked, n_valid=n, step=st.step,
+                            loss_ema=st.loss_ema)
+                    params, opt_state, metrics = out[:3]
+                    base_step, base_tok = st.step, st.tokens_seen
+                    st.params, st.opt_state = params, opt_state
+                    st.step += n
+                    st.tokens_seen += (n * phase.batch_size
+                                       * self.cfg.seq_len)
+                    pending.append((base_step, base_tok, phase, metrics,
+                                    n))
+                    if self.controller is not None:
+                        with TraceAnnotation("repro.train.cut"):
+                            st.loss_ema = float(jax.device_get(out[3]))
+                            if self.controller.observe_smoothed(
+                                    st.loss_ema, n):
+                                self._fire_cut(loader, stacked)
+                                rechunk = True
+                    if st.step // le > base_step // le:
+                        self._flush(pending, log_cb, t0)
+                    if (se and checkpoint_path
+                            and st.step // se > base_step // se):
+                        with TraceAnnotation("repro.train.checkpoint"):
+                            self.save_checkpoint(checkpoint_path,
+                                                 block=not async_save)
+                    if stop_fn is not None:
+                        with TraceAnnotation("repro.train.hook"):
+                            stop = bool(stop_fn())
+        self._flush(pending, log_cb, t0)
         return self.history
 
     def _fire_cut(self, loader, stacked) -> None:
