@@ -3,8 +3,9 @@
 Routes attention, RMSNorm, and the SSD chunk scan through a selectable
 backend:
 
-  ``xla``              — the stock jnp/lax paths the models have always
-                         run (``models.attention.chunked_attention``,
+  ``xla``              — the stock jnp/lax paths
+                         (``models.attention.causal_attention``, or
+                         ``chunked_attention`` when not causal,
                          ``ref.rmsnorm_ref``, ``models.mamba2.
                          ssd_chunked``); the default.
   ``pallas``           — the fused Pallas TPU kernels in this package,
@@ -81,6 +82,8 @@ def attention(q, k, v, *, causal: bool = True,
     them — outputs and gradients for real rows are unaffected, and the
     padded query rows are sliced off).  Non-causal ragged tails would
     attend to the padding, so they fall back to the XLA path instead.
+    The ``xla`` entry is ``causal_attention`` (which pads the same way
+    to its own block), or ``chunked_attention`` when not causal.
     """
     backend = resolve(backend)
     S = q.shape[1]
@@ -110,8 +113,10 @@ def attention(q, k, v, *, causal: bool = True,
             if pad:
                 out = out[:, :, :S]
             return jnp.swapaxes(out, 1, 2)
-    from repro.models.attention import chunked_attention  # import cycle
-    return chunked_attention(q, k, v, causal=causal)
+    from repro.models import attention as A  # import cycle
+    if causal:
+        return A.causal_attention(q, k, v)
+    return A.chunked_attention(q, k, v, causal=False)
 
 
 def paged_decode_attention(q, k, v, lengths, *, backend: str | None = None,

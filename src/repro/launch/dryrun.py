@@ -92,8 +92,7 @@ def collective_bytes(hlo_text: str):
 
 
 def run_one(arch: str, shape_name: str, *, multi_pod: bool,
-            out_dir: str, block_skip: bool = False,
-            seq_shard: bool = True, remat_policy: str = "",
+            out_dir: str, seq_shard: bool = True, remat_policy: str = "",
             serve_resident: bool = False, capacity_factor: float = 0.0,
             cache_seq_shard: bool = False, mesh_shape: str = "",
             tag: str = "") -> dict:
@@ -122,8 +121,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         fn, args, in_specs, out_specs = ST.build_workload(
-            cfg, shape, multi_pod=multi_pod, block_skip=block_skip,
-            seq_shard=seq_shard, remat_policy=remat_policy,
+            cfg, shape, multi_pod=multi_pod, seq_shard=seq_shard, remat_policy=remat_policy,
             serve_resident=serve_resident,
             cache_seq_shard=cache_seq_shard)
         with jax.set_mesh(mesh):
@@ -203,8 +201,6 @@ def main():
     ap.add_argument("--multipod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--block-skip", action="store_true",
-                    help="enable triangular-blocking attention (perf)")
     ap.add_argument("--no-seq-shard", action="store_true")
     ap.add_argument("--remat-policy", default="")
     ap.add_argument("--serve-resident", action="store_true")
@@ -244,7 +240,6 @@ def main():
                         continue
                 results.append(run_one(
                     arch, shp, multi_pod=mp, out_dir=args.out,
-                    block_skip=args.block_skip,
                     seq_shard=not args.no_seq_shard,
                     remat_policy=args.remat_policy,
                     serve_resident=args.serve_resident,

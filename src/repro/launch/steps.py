@@ -76,7 +76,7 @@ def shape_supported(cfg: ModelConfig, shape: InputShape) -> Tuple[bool, str]:
 def build_workload(cfg: ModelConfig, shape: InputShape, *,
                    multi_pod: bool = False, opt_kind: str = "adamw",
                    z_loss: float = 0.0, remat: bool = True,
-                   block_skip: bool = False, seq_shard: bool = True,
+                   seq_shard: bool = True,
                    remat_policy: str = "", serve_resident: bool = False,
                    cache_seq_shard: bool = False,
                    dtype=jnp.bfloat16):
@@ -95,8 +95,7 @@ def build_workload(cfg: ModelConfig, shape: InputShape, *,
         ospec = opt_state_specs(pspec, ostruct)
         step = make_grad_step(cfg, opt, z_loss=z_loss, dtype=dtype,
                               remat=remat, multi_pod=multi_pod,
-                              block_skip=block_skip, seq_shard=seq_shard,
-                              remat_policy=remat_policy)
+                              seq_shard=seq_shard, remat_policy=remat_policy)
 
         def train_step(params, opt_state, batch, lr):
             new_params, new_opt, metrics = step(params, opt_state,

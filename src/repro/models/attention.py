@@ -1,17 +1,24 @@
-"""GQA attention: flash-style chunked online-softmax (XLA path), RoPE,
-sliding windows, full and ring KV caches.
+"""GQA attention on the XLA path, RoPE, sliding windows, full and ring
+KV caches.
 
-The chunked `lax.scan` formulation bounds activation memory to
-O(S · chunk) instead of O(S²) — this is the TPU-native adaptation of
-flash attention used for distributed lowering; the Pallas kernel in
-``repro.kernels.flash_attention`` is the single-core hot-spot version.
+Two cores:
 
-``block_skip=True`` switches to triangular blocking: each query chunk
-only attends to the key chunks its causal/window mask can reach, halving
-attention FLOPs at long sequence length (a beyond-paper §Perf lever).
+- ``causal_attention``: causal self-attention from position 0 (training
+  and prefill).  Query blocks attend to their key prefix only, so the
+  blocks above the diagonal are never computed, and a custom VJP keeps
+  only ``q, k, v, o`` and the row log-sum-exp for the backward, which
+  recomputes the probabilities block by block: no S x S tensor exists
+  in either pass.
+- ``chunked_attention``: online softmax over kv chunks in a
+  ``lax.scan``, for everything else (decode at scalar or per-request
+  offsets, ring caches, sliding windows, non-causal encoders).
+
+The Pallas kernel in ``repro.kernels.flash_attention`` is the fused
+TPU version of the first.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -91,8 +98,7 @@ def _attend(q, k, v, qpos, kpos, *, causal, window, kv_len, scale):
 
 
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                      kv_len=None, kpos=None, chunk=1024,
-                      block_skip=False):
+                      kv_len=None, kpos=None, chunk=1024):
     """Online-softmax attention, scanning kv chunks.
 
     q: (B, Sq, H, hd); k,v: (B, Sk, Hkv, hd).
@@ -115,10 +121,6 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
         kpos = jnp.concatenate([kpos, jnp.full((pad,), -1, kpos.dtype)])
         Sk += pad
     n_kv = Sk // chunk
-
-    if block_skip and causal and window is None and Sq == Sk and Sq % chunk == 0:
-        return _attention_block_skip(q, k, v, qpos, kpos, chunk, scale,
-                                     kv_len)
 
     ks = jnp.moveaxis(k.reshape(B, n_kv, chunk, Hkv, hd), 1, 0)
     vs = jnp.moveaxis(v.reshape(B, n_kv, chunk, Hkv, hd), 1, 0)
@@ -152,47 +154,122 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     return out.astype(q.dtype)
 
 
-def _attention_block_skip(q, k, v, qpos, kpos, chunk, scale, kv_len):
-    """Triangular blocking: query chunk i only visits key chunks 0..i."""
-    B, Sq, H, hd = q.shape
+# --------------------------------------------------------------------- #
+# causal blocks with a saved-statistics backward
+# --------------------------------------------------------------------- #
+
+def _block(S: int) -> int:
+    """Query-block length for a sequence of S: 256 rows (one block when
+    S is shorter), widened so that no sequence has more than 32 blocks
+    (the blocks unroll into the program)."""
+    if S <= 256:
+        return S
+    return max(256, 128 * -(-S // (32 * 128)))
+
+
+def causal_attention(q, k, v):
+    """Causal self-attention from position 0: q (B, S, H, hd), k/v
+    (B, S, Hkv, hd) → (B, S, H, hd).
+
+    Query block i (``_block(S)`` rows) attends to keys
+    ``[0, (i+1)·blk)`` with a full softmax over that prefix; only its
+    diagonal block is masked, and the blocks above the diagonal are
+    never computed.  Scores, probabilities and the statistics are
+    float32; ``QKᵀ`` takes the inputs as they are with a float32
+    result.  A ragged S is zero-padded to a block multiple: the padded
+    keys sit above every real query's causal horizon, and the padded
+    query rows are sliced off."""
+    S = q.shape[1]
+    blk = _block(S)
+    pad = (-S) % blk
+    if pad:
+        cfg = ((0, 0), (0, pad), (0, 0), (0, 0))
+        q, k, v = (jnp.pad(x, cfg) for x in (q, k, v))
+    with jax.named_scope("causal_blocks"):
+        o = _causal_blocks(q, k, v, blk)
+    return o[:, :S] if pad else o
+
+
+def _scores(qi, k, i: int, blk: int, scale: float):
+    """Masked f32 scores (B, Hkv, G, blk, e) of query block i, qi
+    (B, blk, Hkv, G, hd), against its key prefix k (B, e, Hkv, hd)."""
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qi, k,
+                   preferred_element_type=jnp.float32) * scale
+    qpos = i * blk + jnp.arange(blk)
+    kpos = jnp.arange(k.shape[1])
+    return jnp.where(kpos[None, :] <= qpos[:, None], s, NEG_INF)
+
+
+def _split(x, Hkv: int):
+    """(B, S, H, hd) → (B, S, Hkv, G, hd)."""
+    B, S, H, hd = x.shape
+    return x.reshape(B, S, Hkv, H // Hkv, hd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _causal_blocks(q, k, v, blk: int):
+    return _causal_fwd(q, k, v, blk)[0]
+
+
+def _causal_fwd(q, k, v, blk: int):
+    B, S, H, hd = q.shape
     Hkv = k.shape[2]
-    G = H // Hkv
-    n = Sq // chunk
-    outs = []
+    scale = 1.0 / math.sqrt(hd)
+    qg = _split(q, Hkv)
+    outs, lses = [], []
+    for i in range(S // blk):
+        e = (i + 1) * blk
+        s = _scores(qg[:, i * blk:e], k[:, :e], i, blk, scale)
+        m = jnp.max(s, axis=-1)                          # (B,Hkv,G,blk)
+        p = jnp.exp(s - m[..., None])
+        l = jnp.sum(p, axis=-1)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", p,
+                       v[:, :e].astype(jnp.float32))
+        outs.append(o / jnp.moveaxis(l, 3, 1)[..., None])
+        lses.append(m + jnp.log(l))
+    o = jnp.concatenate(outs, axis=1).reshape(B, S, H, hd)
+    lse = jnp.concatenate(lses, axis=-1)                 # (B,Hkv,G,S)
+    return o.astype(q.dtype), (q, k, v, o, lse)
+
+
+def _causal_bwd(blk: int, res, do):
+    q, k, v, o, lse = res
+    B, S, H, hd = q.shape
+    Hkv = k.shape[2]
+    scale = 1.0 / math.sqrt(hd)
+    qg = _split(q, Hkv)
+    dog = _split(do.astype(jnp.float32), Hkv)
+    # Δ = rowsum(dO ∘ O), once: (B,Hkv,G,S)
+    delta = jnp.moveaxis(jnp.sum(dog * _split(o, Hkv), axis=-1), 1, 3)
+    n = S // blk
+    dqs, dks, dvs = [], [], []
     for i in range(n):
-        qi = q[:, i * chunk:(i + 1) * chunk]
-        qp = qpos[i * chunk:(i + 1) * chunk]
-        ki = k[:, : (i + 1) * chunk]
-        vi = v[:, : (i + 1) * chunk]
-        kp = kpos[: (i + 1) * chunk]
-        if i == 0:
-            o, m, l = _attend(qi, ki, vi, qp, kp, causal=True, window=None,
-                              kv_len=kv_len, scale=scale)
-            out = o / jnp.maximum(l, 1e-30)[..., None]
-        else:
-            ks = jnp.moveaxis(ki.reshape(B, i + 1, chunk, Hkv, hd), 1, 0)
-            vs = jnp.moveaxis(vi.reshape(B, i + 1, chunk, Hkv, hd), 1, 0)
-            kps = kp.reshape(i + 1, chunk)
-            acc0 = jnp.zeros((B, Hkv, G, chunk, hd), jnp.float32)
-            m0 = jnp.full((B, Hkv, G, chunk), NEG_INF, jnp.float32)
-            l0 = jnp.zeros((B, Hkv, G, chunk), jnp.float32)
+        e = (i + 1) * blk
+        rows = slice(i * blk, e)
+        qi, doi = qg[:, rows], dog[:, rows]
+        kp = k[:, :e].astype(jnp.float32)
+        s = _scores(qi, k[:, :e], i, blk, scale)
+        p = jnp.exp(s - lse[..., rows, None])
+        dvs.append(jnp.einsum("bhgqk,bqhgd->bkhd", p, doi))
+        dp = jnp.einsum("bqhgd,bkhd->bhgqk", doi,
+                        v[:, :e].astype(jnp.float32))
+        ds = p * (dp - delta[..., rows, None])
+        dqs.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, kp) * scale)
+        dks.append(jnp.einsum("bhgqk,bqhgd->bkhd", ds,
+                              qi.astype(jnp.float32)) * scale)
 
-            def body(carry, xs, qi=qi, qp=qp):
-                acc, m, l = carry
-                kc, vc, kpc = xs
-                o_c, m_c, l_c = _attend(qi, kc, vc, qp, kpc, causal=True,
-                                        window=None, kv_len=kv_len,
-                                        scale=scale)
-                m_new = jnp.maximum(m, m_c)
-                corr, corr_c = jnp.exp(m - m_new), jnp.exp(m_c - m_new)
-                acc = acc * corr[..., None] + o_c * corr_c[..., None]
-                return (acc, m_new, l * corr + l_c * corr_c), None
+    def by_key_block(parts):
+        # key block j gathers the contributions of query blocks i >= j
+        return jnp.concatenate(
+            [sum(parts[i][:, j * blk:(j + 1) * blk] for i in range(j, n))
+             for j in range(n)], axis=1)
 
-            (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
-                                          (ks, vs, kps))
-            out = acc / jnp.maximum(l, 1e-30)[..., None]
-        outs.append(jnp.moveaxis(out, 3, 1).reshape(B, chunk, H, hd))
-    return jnp.concatenate(outs, axis=1).astype(q.dtype)
+    dq = jnp.concatenate(dqs, axis=1).reshape(B, S, H, hd)
+    return (dq.astype(q.dtype), by_key_block(dks).astype(k.dtype),
+            by_key_block(dvs).astype(v.dtype))
+
+
+_causal_blocks.defvjp(_causal_fwd, _causal_bwd)
 
 
 # --------------------------------------------------------------------- #
@@ -202,13 +279,12 @@ def _attention_block_skip(q, k, v, qpos, kpos, chunk, scale, kv_len):
 def attn_forward(params: Params, x, *, n_heads: int, n_kv_heads: int,
                  head_dim: int, rope_theta: float, causal: bool = True,
                  window: Optional[int] = None, positions=None,
-                 chunk: int = 1024, block_skip: bool = False,
-                 backend: str = "xla"):
+                 chunk: int = 1024, backend: str = "xla"):
     """Training/prefill self-attention over x: (B, S, d).
 
-    ``backend`` selects the kernel backend for the core attention op
-    (see repro.kernels.backend); sliding-window attention has no Pallas
-    kernel yet, so windowed layers stay on the XLA chunked scan."""
+    Causal attention without a window goes through the kernel registry
+    (``backend``; its ``xla`` entry is ``causal_attention``); windowed
+    and non-causal layers take the chunked scan."""
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)[None, :]
@@ -220,11 +296,11 @@ def attn_forward(params: Params, x, *, n_heads: int, n_kv_heads: int,
     if rope_theta:
         q = apply_rope(q, positions, rope_theta)
         k = apply_rope(k, positions, rope_theta)
-    if backend != "xla" and window is None and causal:
+    if window is None and causal:
         o = KB.attention(q, k, v, causal=True, backend=backend)
     else:
         o = chunked_attention(q, k, v, causal=causal, window=window,
-                              chunk=chunk, block_skip=block_skip)
+                              chunk=chunk)
     o = o.reshape(B, S, n_heads * head_dim)
     out = o @ params["w_o"].astype(x.dtype)
     return out, (k, v)

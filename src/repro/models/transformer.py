@@ -81,7 +81,7 @@ def param_specs(cfg: ModelConfig, multi_pod: bool = False) -> Params:
 # --------------------------------------------------------------------- #
 
 def _layer(pl: Params, x, cfg: ModelConfig, *, res_spec,
-           block_skip: bool = False, chunk: int = 1024):
+           chunk: int = 1024):
     batch_axes = res_spec[0] if isinstance(res_spec, P) else None
     kb = cfg.kernel_backend
     h = rmsnorm(x, pl["norm1"], cfg.norm_eps, backend=kb)
@@ -91,7 +91,7 @@ def _layer(pl: Params, x, cfg: ModelConfig, *, res_spec,
                               head_dim=cfg.head_dim,
                               rope_theta=cfg.rope_theta, causal=True,
                               window=cfg.sliding_window, chunk=chunk,
-                              block_skip=block_skip, backend=kb)
+                              backend=kb)
     x = x + a
     x = constrain(x, res_spec)
     h = rmsnorm(x, pl["norm2"], cfg.norm_eps, backend=kb)
@@ -111,7 +111,7 @@ def _layer(pl: Params, x, cfg: ModelConfig, *, res_spec,
 
 def forward_hidden(params: Params, cfg: ModelConfig, tokens, *,
                    prefix_emb=None, dtype=jnp.bfloat16, remat: bool = True,
-                   multi_pod: bool = False, block_skip: bool = False,
+                   multi_pod: bool = False,
                    attn_chunk: int = 1024, seq_shard: bool = True,
                    remat_policy: str = ""):
     """tokens: (B, S_text) int32 → final hidden states (B, S, d) where
@@ -127,8 +127,7 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens, *,
     x = constrain(x, res_spec)
 
     def body(x, pl):
-        y, aux = _layer(pl, x, cfg, res_spec=res_spec,
-                        block_skip=block_skip, chunk=attn_chunk)
+        y, aux = _layer(pl, x, cfg, res_spec=res_spec, chunk=attn_chunk)
         aux = {k: jnp.asarray(v, jnp.float32) for k, v in aux.items()}
         return y, aux
 
@@ -149,7 +148,7 @@ def forward_hidden(params: Params, cfg: ModelConfig, tokens, *,
 
 def loss_fn(params: Params, cfg: ModelConfig, batch: Params, *,
             z_loss: float = 0.0, dtype=jnp.bfloat16, remat: bool = True,
-            multi_pod: bool = False, block_skip: bool = False,
+            multi_pod: bool = False,
             seq_shard: bool = True, remat_policy: str = ""):
     """batch: tokens (B,S_text), labels (B,S_text), optional prefix_emb.
     Returns (loss, metrics)."""
@@ -157,8 +156,7 @@ def loss_fn(params: Params, cfg: ModelConfig, batch: Params, *,
     prefix = batch.get("prefix_emb")
     h, aux = forward_hidden(params, cfg, tokens, prefix_emb=prefix,
                             dtype=dtype, remat=remat, multi_pod=multi_pod,
-                            block_skip=block_skip, seq_shard=seq_shard,
-                            remat_policy=remat_policy)
+                            seq_shard=seq_shard, remat_policy=remat_policy)
     labels = batch["labels"]
     mask = batch.get("mask", jnp.ones(labels.shape, jnp.float32))
     h = constrain(h, P(fsdp_axis(multi_pod), None, None))

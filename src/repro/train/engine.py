@@ -92,8 +92,8 @@ def make_grad_step(cfg: ModelConfig, optimizer: O.Optimizer, *,
                    multi_pod: bool = False, **loss_kw) -> Callable:
     """The one training step builder: ``step(params, opt_state, batch,
     lr) → (params, opt_state, metrics)``.  jit-able; batch shapes decide
-    the compile cache key.  Extra ``loss_kw`` (block_skip, seq_shard,
-    remat_policy, …) forward to the family loss function."""
+    the compile cache key.  Extra ``loss_kw`` (seq_shard, remat_policy,
+    …) forward to the family loss function."""
 
     def loss_of(params, batch):
         # named scopes mark the HLO metadata only: the device trace
