@@ -10,7 +10,10 @@ step's metrics, so a step boundary is a finished step on the device.
 
 After the window the trainer is freed and the plain reference that the
 configuration names (``common.reference``) retraces the checked steps
-from the same weights and rows (``compare``).
+from the same weights and rows (``compare``).  A traced run first takes
+what the per-layer readers read of the program (:func:`program_layer`):
+the op-name map of the step that ran, the step's own scalars over the
+window, and the work per scope that the reference counts.
 """
 from __future__ import annotations
 
@@ -26,7 +29,7 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from chipbench import common, compare, weights
+from chipbench import common, compare, scopes, weights
 from chipbench.traffic_gen import MarkovSource
 
 # the keys of a configuration and of a traffic file that this driver
@@ -68,15 +71,23 @@ def run_config(cfg: Dict, traffic: Dict):
         log_every=traffic["log_every"])
 
 
+# the keys of a step's history row that the trainer writes itself; the
+# others are scalars the step returns beside its loss
+HISTORY_KEYS = frozenset({"step", "tokens", "lr", "batch_size", "phase",
+                          "loss", "wall"})
+
+
 class TimedLoader:
     """The program's loader with each ``next()`` of its chunk stream
     timed and wrapped in a ``chipbench.loader_next`` span.  A planted
-    fault may rewrite the chunk the step receives."""
+    fault may rewrite the chunk the step receives; ``last`` is the last
+    chunk the step received."""
 
     def __init__(self, loader, rewrite=None):
         self.loader = loader
         self.rewrite = rewrite
         self.waits: List[tuple] = []        # (start, seconds)
+        self.last = None
 
     def __getattr__(self, name):
         return getattr(self.loader, name)
@@ -93,6 +104,7 @@ class TimedLoader:
             self.waits.append((t, time.perf_counter() - t))
             if self.rewrite is not None:
                 chunk = self.rewrite(chunk)
+            self.last = chunk
             yield phase, chunk, m
 
 
@@ -202,6 +214,8 @@ def run(ctx: Dict) -> Dict:
     loader_wait = sum(s for t, s in loader.waits
                       if win["t0"] <= t < win["t1"])
     mem = common.memory_peak_bytes(jax.devices()[:ctx["chips"]])
+    program = (program_layer(ctx, trainer, loader.last, win)
+               if tracer.on else {})
     mesh = trainer.mesh
     del trainer, loader
     gc.collect()
@@ -226,11 +240,44 @@ def run(ctx: Dict) -> Dict:
                   "loader_wait_s": loader_wait,
                   "flops_per_token": common.reference(cfg)
                   .train_flops_per_token(cfg["model"], traffic["seq_len"]),
-                  "chips": ctx["chips"]},
+                  "tokens_per_execution": traffic["fuse_steps"]
+                  * tok_per_step,
+                  "chips": ctx["chips"], **program},
         "notes": {"window_steps": steps, "reference_s": ref["seconds"],
                   "checked_losses": prog["losses"],
                   "reference_losses": ref["losses"]},
     }
+
+
+def program_layer(ctx: Dict, trainer, chunk, win: Dict) -> Dict:
+    """What a traced run's readers take from the program, read once the
+    window has closed: ``op_names``, the op-name map of the step that
+    ran (from the persistent compilation cache); ``counters``, the mean
+    over the window's steps of every scalar the step returns beside
+    its loss; and ``scope_work``, the reference's required work per
+    token in each scope it counts (where it counts any)."""
+    cfg, traffic = ctx["config"], ctx["traffic"]
+    out = {"op_names": scopes.train_step_op_names(trainer.engine, chunk),
+           "counters": window_counters(trainer.history, win["step0"],
+                                       win["step1"])}
+    work = getattr(common.reference(cfg), "scope_work", None)
+    if work is not None:
+        out["scope_work"] = work(
+            cfg["model"], traffic["seq_len"],
+            itemsize=jnp.dtype(cfg["compute_dtype"]).itemsize)
+    return out
+
+
+def window_counters(history: List[Dict], step0: int, step1: int
+                    ) -> Dict[str, float]:
+    """Mean of each numeric key of the trainer's history rows other than
+    :data:`HISTORY_KEYS`, over steps ``step0 + 1 .. step1``."""
+    rows = [r for r in history if step0 < r["step"] <= step1]
+    names = sorted({k for r in rows for k, v in r.items()
+                    if k not in HISTORY_KEYS
+                    and isinstance(v, (int, float))})
+    return {k: float(np.mean([r[k] for r in rows if k in r]))
+            for k in names}
 
 
 def calibrate(ctx: Dict, source: MarkovSource, mesh, ref: Dict) -> Dict:

@@ -16,6 +16,11 @@ utilization by recounting:
 
 After ``benchmarks/flops_model.py``'s dense-layer formulas, with its
 remat factor (x4) and full-square attention dropped.
+
+Bytes, for a roofline share, count the same way: what the pass must
+read from and write to HBM at least, each tensor once per pass, in the
+compute dtype; nothing recomputed, nothing re-read for lack of on-chip
+memory.
 """
 from __future__ import annotations
 
@@ -50,3 +55,21 @@ def forward_flops_per_token(m: Dict, seq_len: int) -> float:
 
 def train_flops_per_token(m: Dict, seq_len: int) -> float:
     return 3 * forward_flops_per_token(m, seq_len)
+
+
+def causal_core_per_token(m: Dict, seq_len: int, itemsize: int
+                          ) -> Dict[str, float]:
+    """Required work of the causal attention core (scores, softmax and
+    the value product; not the projections or rotary) per token, over
+    all layers, forward and backward: ``{"flops", "bytes"}``.
+
+    FLOPs: 3 x 2 x 2 x q_dim x S / 2 a layer (two products of the lower
+    triangle, a multiply-add 2 FLOPs, training 3 x forward).  Bytes: the
+    forward reads q, k, v and writes o; the backward reads q, k, v, o
+    and dO and writes dq, dk, dv: 6 q_dim + 6 kv_dim values a layer
+    (the per-row softmax statistics, H values, are left out)."""
+    q = m["n_heads"] * m["head_dim"]
+    kv = m["n_kv_heads"] * m["head_dim"]
+    L = m["n_layers"]
+    return {"flops": L * 3 * 2 * 2 * q * seq_len / 2,
+            "bytes": L * itemsize * (6 * q + 6 * kv)}

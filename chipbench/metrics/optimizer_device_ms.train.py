@@ -1,0 +1,9 @@
+"""Device milliseconds per train-step execution in the optimizer: ops under
+the program's ``optimizer`` scope (clip, AdamW, the gradient norm); leaf
+ops, mean over chips (device trace, classed by ``chipbench/scopes.py``).
+Nothing to read where no op of the class ran."""
+from chipbench import program_trace as PT
+
+
+def read(layer):
+    return PT.scope_reading(layer, PT.ALL, "optimizer")

@@ -33,9 +33,18 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from chipbench.flops import param_count, train_flops_per_token  # noqa: F401
+from chipbench.flops import (causal_core_per_token, param_count,  # noqa: F401
+                             train_flops_per_token)
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def scope_work(m: Dict, seq_len: int, itemsize: int
+               ) -> Dict[str, Dict[str, float]]:
+    """Required work per token by the program's scope that does it
+    (:mod:`chipbench.flops`): the blocked causal core,
+    ``causal_blocks``."""
+    return {"causal_blocks": causal_core_per_token(m, seq_len, itemsize)}
 
 
 # --------------------------------------------------------------------- #

@@ -53,22 +53,22 @@ class Tracer:
             jax.profiler.stop_trace()
 
     def reduce(self):
-        from chipbench import trace
-        tr = trace.load(trace.find_xplane(self.dir))
-        t0, t1 = trace.window_of(tr)
+        from chipbench import program_trace, trace
+        tr = program_trace.load(trace.find_xplane(self.dir))
         shutil.rmtree(self.dir, ignore_errors=True)
-        return trace.reduce(tr, t0, t1)
+        return program_trace.reduce_window(tr)
 
 
 def execute(name: str, seed: int, seconds: float, traced: bool, *,
             root: Path = ROOT, require_chip: bool = True,
             fault: str = None, spec: dict = None,
-            t_proc: float = T_PROC, extra: dict = None) -> dict:
+            t_proc: float = T_PROC, extra: dict = None,
+            trace_dir: Path = common.TRACE_DIR) -> dict:
     """Run the cell and return the result object.  ``spec`` (tests)
     replaces what :func:`common.load_cell` reads; ``require_chip=False``
     (tests) skips the look for TPU chips; ``extra`` is merged into the
     driver's context and receives its ``calibration`` readings
-    (``calibrate.py``)."""
+    (``calibrate.py``) and the readers' ``layer``."""
     import jax
     spec = spec or common.load_cell(name, root)
     chips = spec["cell"]["chips"]
@@ -88,7 +88,7 @@ def execute(name: str, seed: int, seconds: float, traced: bool, *,
                       drv.CONFIG_KEYS)
     common.check_keys(f"traffic of {name}", spec["traffic"],
                       drv.TRAFFIC_KEYS)
-    tracer = Tracer(traced)
+    tracer = Tracer(traced, trace_dir)
     ctx = {"config": spec["config"], "traffic": spec["traffic"],
            "limits": spec["limits"], "chips": chips, "seed": seed,
            "seconds": seconds, "t_proc": t_proc, "tracer": tracer,
@@ -96,24 +96,33 @@ def execute(name: str, seed: int, seconds: float, traced: bool, *,
     rec = drv.run(ctx)
     if extra is not None:
         extra["calibration"] = ctx.get("calibration") or {}
+        extra["layer"] = rec["layer"]
     print(json.dumps({"setup": log.snapshot(),
                       "notes": rec.get("notes", {})}, default=str),
           flush=True)
     device["memory_peak_bytes"] = rec["memory_peak_bytes"]
     breakdown = None
     if traced:
-        red = tracer.reduce()
-        rec["layer"]["trace"] = red
-        rec["layer"]["peaks"] = (common.peaks(device["kind"], root)
-                                 if device["platform"] == "tpu" else None)
+        from chipbench import program_trace
+        layer = rec["layer"]
+        red = layer["trace"] = tracer.reduce()
+        layer["peaks"] = (common.peaks(device["kind"], root)
+                          if device["platform"] == "tpu" else None)
+        top = red["top_ops"]
+        if "op_names" in layer:
+            layer["scopes"] = program_trace.scope_table(red["program"],
+                                                        layer["op_names"])
+            top = [(program_trace.op_label(n, layer["op_names"]), t)
+                   for n, t in top]
+            print(json.dumps(program_trace.summary(layer)), flush=True)
         metrics = {}
         for m in spec["per_layer"]:
-            v = common.metric_reader(m["name"], root).read(rec["layer"])
+            v = common.metric_reader(m["name"], root).read(layer)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
-        breakdown = {"device_ops": [list(x) for x in red["top_ops"]],
+        breakdown = {"device_ops": [list(x) for x in top],
                      "idle_gaps": [list(x) for x in red["idle_gaps"]]}
     else:
         values = dict(rec["end_to_end"], setup_s=rec["setup_s"])

@@ -25,7 +25,7 @@ across these: attention ops count in their pass too.
 from __future__ import annotations
 
 import re
-from typing import Dict
+from typing import Dict, List
 
 CLASSES = ("forward", "backward", "recompute", "optimizer", "unscoped")
 
@@ -43,7 +43,6 @@ def _scope(name: str) -> re.Pattern:
 
 _FORWARD = _scope("forward")
 _OPTIMIZER = _scope("optimizer")
-_ATTENTION = _scope("attention")
 
 
 def op_names(hlo_text: str) -> Dict[str, str]:
@@ -72,8 +71,13 @@ def classify(instr: str, op_name: str) -> str:
     return "unscoped"
 
 
-def is_attention(op_name: str) -> bool:
-    return bool(_ATTENTION.search(op_name))
+def components(op_name: str) -> List[str]:
+    """The name-stack components of an ``op_name`` in order, bare or
+    inside a transform's parens: ``a/transpose(jvp(forward))/dot`` →
+    ``[a, transpose, jvp, forward, dot]``.  A scope covers an op where
+    its name is one of them (``attention`` covers
+    ``a/checkpoint/attention/dot``, not ``a/dot_product_attention``)."""
+    return [c for c in re.split(r"[/();]", op_name) if c]
 
 
 def train_step_op_names(engine, stacked_batch) -> Dict[str, str]:

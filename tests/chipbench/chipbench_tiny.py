@@ -30,3 +30,10 @@ def spec(cell: str, chips: int = None) -> dict:
     if chips is not None:
         s["cell"] = dict(s["cell"], chips=chips)
     return s
+
+
+def train_cells() -> list:
+    """The manifest's training cells."""
+    from chipbench import common
+    return [w["name"] for w in common.manifest()["workloads"]
+            if common.load_cell(w["name"])["traffic"]["kind"] == "train"]

@@ -15,6 +15,7 @@ from chipbench import common
 from chipbench import run as R
 
 TRAIN = "train-300m-b512-4x1"
+TRAIN_CELLS = chipbench_tiny.train_cells()
 SEED = 2 ** 31 + 101
 
 
@@ -24,16 +25,18 @@ def _run(cell, fault=None, chips=1):
                      fault=fault, t_proc=time.perf_counter())
 
 
-def test_train_sound_run_is_correct():
-    out = _run(TRAIN)
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
+def test_train_sound_run_is_correct(cell):
+    out = _run(cell)
     assert out["correct"], out["checks"]
     assert out["attempted"] > 2 and out["failed"] == 0
     assert out["metrics"]["train_tokens_per_s"]["value"] > 0
 
 
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
-def test_train_fault_is_not_correct(fault):
-    out = _run(TRAIN, fault)
+def test_train_fault_is_not_correct(fault, cell):
+    out = _run(cell, fault)
     assert not out["correct"], out["checks"]
 
 

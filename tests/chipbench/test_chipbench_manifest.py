@@ -2,12 +2,14 @@
 names."""
 import json
 import re
+from pathlib import Path
 
 import pytest
 
 from chipbench import common
 
 ROOT = common.ROOT
+DATA = Path(__file__).resolve().parent / "data"
 MAN = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -136,6 +138,82 @@ def test_every_key_of_a_cell_file_is_read_or_descriptive(cell):
                                  if k != "reference"}, drv.CONFIG_KEYS)
 
 
+# a width may never be cut: hidden, intermediate, latent, state or
+# projection sizes, ``*_dim``/``*_rank``, head sizes, expansion
+# factors, experts per token
+WIDTH = re.compile(r"(_dim|_rank)$|^d_|d_ff|hidden|intermediate|latent"
+                   r"|state_size|proj|expan|per_tok|top_k")
+
+
+def _model_has(model, dotted):
+    node = model
+    for part in dotted.split("."):
+        if not isinstance(node, dict) or part not in node:
+            return False
+        node = node[part]
+    return True
+
+
+def reduced_problems(cfg, listed):
+    """What is wrong with a configuration file's cut: ``listed`` is its
+    manifest entry's ``reduced``.  Each reduced key names a key of the
+    file's ``model`` (a nested one by a dotted path) and no width; a
+    cut file states ``assumed`` and a ``deployment`` with the published
+    value of each reduced key and the chips that share a layer."""
+    red = cfg.get("reduced")
+    out = [] if red == listed else [f"reduced {red} != manifest {listed}"]
+    for k in red or []:
+        if not NAME.match(k):
+            out.append(f"{k!r} is no name")
+        if not _model_has(cfg["model"], k):
+            out.append(f"{k!r} is not a key of model")
+        if WIDTH.search(k.rsplit(".", 1)[-1]):
+            out.append(f"{k!r} is a width")
+    if red:
+        dep = cfg.get("deployment")
+        if not isinstance(dep, dict):
+            return out + ["a cut file states its deployment as an object"]
+        if set(dep.get("published", {})) != set(red):
+            out.append("deployment.published names each reduced key")
+        chips = dep.get("chips_per_layer")
+        if not (isinstance(chips, int) and chips >= 1):
+            out.append("deployment.chips_per_layer is a whole number")
+        if not cfg.get("assumed"):
+            out.append("a cut file states what it assumed")
+    return out
+
+
+def test_a_cut_configuration_passes_the_rules():
+    cfg = json.loads((DATA / "cut_config.json").read_text())
+    assert cfg["reduced"]
+    assert reduced_problems(cfg, cfg["reduced"]) == []
+
+
+@pytest.mark.parametrize("change,problem", [
+    (lambda c: c["reduced"].append("moe.no_such_key"),
+     "not a key of model"),
+    (lambda c: c["reduced"].append("n_experts"), "not a key of model"),
+    (lambda c: c["reduced"].append("moe.expert_d_ff"), "is a width"),
+    (lambda c: c["reduced"].append("kv_lora_rank"), "is a width"),
+    (lambda c: c["deployment"]["published"].pop("vocab_size"),
+     "published"),
+    (lambda c: c["deployment"].pop("chips_per_layer"), "chips_per_layer"),
+    (lambda c: c.update(deployment="eight chips"), "as an object"),
+    (lambda c: c.pop("assumed"), "assumed"),
+])
+def test_a_bad_cut_is_refused(change, problem):
+    cfg = json.loads((DATA / "cut_config.json").read_text())
+    change(cfg)
+    got = reduced_problems(cfg, cfg["reduced"])
+    assert any(problem in p for p in got), got
+
+
+def test_reduced_is_the_same_list_in_the_manifest_and_the_file():
+    cfg = json.loads((DATA / "cut_config.json").read_text())
+    got = reduced_problems(cfg, cfg["reduced"][:-1])
+    assert any("!= manifest" in p for p in got), got
+
+
 @pytest.mark.parametrize("entry", MAN["configs"], ids=lambda c: c["name"])
 def test_config_files(entry):
     used = {w["config"] for w in MAN["workloads"]}
@@ -143,7 +221,7 @@ def test_config_files(entry):
     assert entry["file"].startswith("chipbench/")
     cfg = json.loads((ROOT / entry["file"]).read_text())
     assert cfg["name"] == entry["name"]
-    assert cfg["reduced"] == entry["reduced"] == []
+    assert reduced_problems(cfg, entry["reduced"]) == []
     ref = common.reference(cfg)
     assert ref.__name__ == f"chipbench.references.{cfg['reference']}"
     for fn in ("init_tree", "param_count", "train_flops_per_token",

@@ -114,3 +114,104 @@ def test_program_slice_scopes_cover_the_busy_time():
         assert by_cls.get("unscoped", 0.0) < 0.1 * sum(by_cls.values())
     ms = PT.scope_ms(red, names)
     assert ms["attention"] > 0
+
+
+# ------------------------------------------------------------------ #
+# the program's readers on the slice, as a traced run assembles them
+# ------------------------------------------------------------------ #
+
+PASS_READERS = {"forward_device_ms.train": "forward",
+                "backward_device_ms.train": "backward",
+                "recompute_device_ms.train": "recompute",
+                "optimizer_device_ms.train": "optimizer",
+                "attention_device_ms.train": "attention"}
+PROGRAM_READERS = sorted(PASS_READERS) + ["step_gap_ms.train",
+                                          "attention_core_roofline.train"]
+
+
+def _slice_layer(op_names=None):
+    """The ``layer`` that ``run.execute`` gives the readers of a traced
+    run of the four-chip cell, on the slice."""
+    from chipbench.references import olmo_dense
+    tr, names = _program_slice()
+    names = names if op_names is None else op_names
+    red = PT.reduce_window(tr)
+    m = common.load_cell("train-300m-b512-4x1")["config"]["model"]
+    return {"kind": "train", "trace": red, "op_names": names,
+            "scopes": PT.scope_table(red["program"], names),
+            "peaks": common.peaks("TPU v5 lite"), "chips": 4,
+            "tokens_per_execution": 512 * 1024,
+            "scope_work": olmo_dense.scope_work(m, 1024, itemsize=2)}
+
+
+def _reader(name, layer):
+    return common.metric_reader(name).read(layer)
+
+
+def test_reduce_window_keeps_the_benchmarks_reduction():
+    tr, _ = _program_slice()
+    t0, t1 = T.window_of(tr)
+    bare = dict(tr, spans=[s for s in tr["spans"]
+                           if s[2].startswith("chipbench.")])
+    red = PT.reduce_window(tr)
+    assert {k: v for k, v in red.items() if k != "program"} \
+        == T.reduce(bare, t0, t1)
+    assert red["program"] == PT.reduce(tr, t0, t1)
+
+
+def test_pass_readers_are_scope_ms_and_the_gap_is_step_gap_ms():
+    layer = _slice_layer()
+    ms = PT.scope_ms(layer["trace"]["program"], layer["op_names"])
+    for name, cls in PASS_READERS.items():
+        assert _reader(name, layer) == pytest.approx(ms[cls]), name
+        assert ms[cls] > 0
+    assert _reader("step_gap_ms.train", layer) == pytest.approx(
+        PT.step_gap_ms(layer["trace"]["program"]))
+    # the classes share out every leaf op of the step
+    assert sum(layer["scopes"][PT.ALL].values()) == pytest.approx(
+        sum(ms[c] for c in scopes.CLASSES))
+
+
+def test_a_scope_the_program_does_not_write_reads_nothing():
+    layer = _slice_layer()
+    # the slice predates the blocked core's scope
+    assert "causal_blocks" not in layer["scopes"]
+    assert _reader("attention_core_roofline.train", layer) is None
+    assert PT.scope_reading(layer, "moe") is None
+    assert PT.counter(layer, "expert_load") is None
+    bare = _slice_layer(op_names={})
+    for name in ("forward_device_ms.train", "backward_device_ms.train",
+                 "optimizer_device_ms.train", "attention_device_ms.train"):
+        assert _reader(name, bare) is None, name
+    untraced = {"kind": "train", "window_s": 51.2, "loader_wait_s": 0.01}
+    for name in PROGRAM_READERS:
+        assert _reader(name, untraced) is None, name
+
+
+def test_attention_core_roofline_where_the_core_is_named():
+    """The slice's attention ops renamed into the core's scope: the
+    required work of a step over their time and the peaks."""
+    _, names = _program_slice()
+    renamed = {i: n.replace("/attention/", "/attention/causal_blocks/")
+               for i, n in names.items()}
+    layer = _slice_layer(op_names=renamed)
+    ms = PT.scope_reading(layer, "causal_blocks")
+    assert ms == pytest.approx(PT.scope_reading(layer, "attention"))
+    work = layer["scope_work"]["causal_blocks"]
+    # 24 layers x 3 x 2 x 2 x 1024 x 512 FLOPs a token
+    assert work["flops"] == 24 * 3 * 2 * 2 * 1024 * 512
+    tokens = 512 * 1024 / 4
+    least = tokens * max(work["flops"] / 197e12, work["bytes"] / 819e9)
+    got = _reader("attention_core_roofline.train", layer)
+    assert got == pytest.approx(100 * least / (ms / 1e3))
+    assert 0 < got < 100
+
+
+def test_breakdown_labels_name_the_class_and_scopes():
+    _, names = _program_slice()
+    instr = next(i for i, n in names.items()
+                 if "rematted_computation/attention/dot_general" in n)
+    got = PT.op_label(f"%{instr} = bf16[8] fusion(%a)", names)
+    assert got.startswith("recompute attention/dot_general: %" + instr)
+    assert PT.op_label("%copy.9 = f32[] copy(%a)", names) \
+        == "%copy.9 = f32[] copy(%a)"

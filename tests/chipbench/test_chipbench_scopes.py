@@ -59,10 +59,16 @@ def test_classify(instr, op_name, cls):
 
 
 def test_attention_is_a_whole_scope_name():
-    assert scopes.is_attention("a/jvp(forward)/attention/dot")
-    assert scopes.is_attention("a/checkpoint/attention")
-    assert not scopes.is_attention("a/dot_product_attention/dot")
-    assert not scopes.is_attention("a/attention_bias/add")
+    def covers(op_name):
+        return "attention" in scopes.components(op_name)
+    assert covers("a/jvp(forward)/attention/dot")
+    assert covers("a/checkpoint/attention")
+    assert not covers("a/dot_product_attention/dot")
+    assert not covers("a/attention_bias/add")
+    assert scopes.components("jit(train_step)/transpose(jvp(forward))/"
+                             "attention/causal_blocks/dot") == [
+        "jit", "train_step", "transpose", "jvp", "forward", "attention",
+        "causal_blocks", "dot"]
 
 
 def test_scope_map_of_the_programs_train_step():
@@ -89,7 +95,8 @@ def test_scope_map_of_the_programs_train_step():
     assert not any("transpose(" in n for n in by_cls["forward"])
     assert all("transpose(jvp(forward))" in n for n in by_cls["backward"])
     assert all("rematted_computation" in n for n in by_cls["recompute"])
-    attn = [n for n in names.values() if scopes.is_attention(n)]
+    attn = [n for n in names.values()
+            if "attention" in scopes.components(n)]
     assert {scopes.classify("", n) for n in attn} >= {
         "forward", "backward", "recompute"}
 
